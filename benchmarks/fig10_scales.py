@@ -73,7 +73,7 @@ def run_point(n_clients: int, chunk_size, rounds: int,
               ragged: bool = True, buffer_dtype: str = "float32",
               state_capacity=None, state_offload: str = "none",
               measure_eviction_error: bool = False,
-              compare_pipeline: bool = False) -> dict:
+              compare_pipeline: bool = False, n_dev=None) -> dict:
     """One scale point, measured in THIS process (run it in a fresh
     subprocess for a clean ru_maxrss high-water mark). Evaluates EVERY
     round so the recorded accuracy list is a genuine trajectory (the
@@ -88,7 +88,10 @@ def run_point(n_clients: int, chunk_size, rounds: int,
     mark the second run could only inflate), and the second-run page-cache
     warmth favors the sync baseline, i.e. biases the reported speedup
     conservatively. Reports sync_s_per_round / pipeline_speedup /
-    pipeline_parity (same-seed trajectory agreement between the two)."""
+    pipeline_parity (same-seed trajectory agreement between the two).
+
+    ``n_dev`` is the device count a sharded point was specified for; a
+    mesh of any other size fails the point instead of measuring it."""
     import gc
 
     from repro.core import compression as C
@@ -116,6 +119,9 @@ def run_point(n_clients: int, chunk_size, rounds: int,
     out = {}
     t0 = time.perf_counter()
     sim = Simulator(build(pipelined))
+    if n_dev is not None and sim.n_dev != n_dev:
+        raise RuntimeError(f"point specified for {n_dev} devices got a "
+                           f"{sim.n_dev}-device mesh")
     h = sim.run()
     wall = time.perf_counter() - t0
     out.update({
@@ -168,7 +174,11 @@ def run_point(n_clients: int, chunk_size, rounds: int,
 
 
 def _subprocess_point(extra_env=None, **kw) -> dict:
-    """Run one point in a fresh interpreter; parse its JSON tail line."""
+    """Run one point in a fresh interpreter; parse its JSON tail line.
+    A parent that had started JAX could hold the accelerator the child
+    needs, so the parent must not have imported it."""
+    if "jax" in sys.modules:
+        raise RuntimeError("the scale-study parent must stay off JAX")
     cmd = [sys.executable, str(Path(__file__).resolve()),
            "--point", json.dumps(kw)]
     env = dict(os.environ)
@@ -301,8 +311,11 @@ def scale_bench(smoke: bool = False) -> dict:
             _subprocess_point(n_clients=2000, chunk_size=None, **base),
             pipelined, explicit, masked_dense,
             # sharded: same 1000-client cohort over 4 forced host devices
+            # (a forced host platform only exists on the CPU backend: on an
+            # accelerator the child sees its own devices and n_dev=4 fails
+            # the point rather than record a smaller mesh)
             _subprocess_point(
-                n_clients=1000, chunk_size=None, sharded=True,
+                n_clients=1000, chunk_size=None, sharded=True, n_dev=4,
                 extra_env={"XLA_FLAGS":
                            "--xla_force_host_platform_device_count=4"},
                 **base),
@@ -431,10 +444,15 @@ def main():
                     help="(internal) run one scale point from a JSON spec "
                          "and print the result JSON")
     args = ap.parse_args()
+    if args.scale or args.smoke:
+        # the parent only spawns the points: it stays off JAX so that each
+        # child can take the accelerator
+        scale_bench(smoke=args.smoke)
+        return
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.point is not None:
         print(json.dumps(run_point(**json.loads(args.point)), default=float))
-    elif args.scale or args.smoke:
-        scale_bench(smoke=args.smoke)
     else:
         run(log=print)
 

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 import time
 from pathlib import Path
 
@@ -211,6 +212,13 @@ def frontier_bench(smoke: bool = False, log=lambda s: None) -> dict:
 # queue-transport load generator
 # ---------------------------------------------------------------------------
 
+def _no_jax_in_producer():
+    """Producer processes are numpy-only: the server process holds the
+    accelerator, and a child that started JAX would contend for it."""
+    if "jax" in sys.modules:
+        raise RuntimeError("a queue producer imported jax")
+
+
 def _producer(queue, producer_id: int, n_uploads: int, n_params: int,
               k: int):
     """One producer process: encode + push ``n_uploads`` realistic top-k
@@ -218,6 +226,7 @@ def _producer(queue, producer_id: int, n_uploads: int, n_params: int,
     touches numpy-side modules (no jax in the producers)."""
     from repro.core import rng as RNG
     from repro.fl import wire as W
+    _no_jax_in_producer()
     rng = RNG.stream(1234, RNG.KIND_FAULTS, 0, producer_id)
     for i in range(n_uploads):
         idx = rng.choice(n_params, size=k, replace=False).astype(np.int64)
@@ -292,6 +301,7 @@ def _soak_producer(queue, results, producer_id: int, n_uploads: int,
     with a blocking sentinel so the server knows this producer drained."""
     from repro.core import rng as RNG
     from repro.fl import wire as W
+    _no_jax_in_producer()
     tr = W.QueueTransport.attach(queue)
     rng = RNG.stream(4321, RNG.KIND_FAULTS, 1, producer_id)
     send_t = {}
@@ -612,6 +622,8 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()
 
 

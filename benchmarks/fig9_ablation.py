@@ -31,4 +31,6 @@ def run(dataset="cifar10", log=lambda s: None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(log=print)

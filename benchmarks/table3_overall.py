@@ -38,4 +38,6 @@ def run(datasets=("har", "cifar10"), log=lambda s: None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(log=print)

@@ -49,17 +49,22 @@ def main():
           f"({toks_s:.1f} tok/s on CPU)")
     print("sample:", jnp.concatenate(out, 1)[0][:16].tolist())
 
-    # Pallas flash-decode kernel (interpret mode on CPU)
+    # Pallas flash-decode kernel: compiled on a TPU, the Pallas interpreter
+    # elsewhere
     from repro.kernels import ops
+    interpret = jax.default_backend() != "tpu"
     B, H, Hkv, D, S = 2, 8, 4, 64, 2048
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
     q = jax.random.normal(ks[0], (B, H, D))
     k = jax.random.normal(ks[1], (B, S, Hkv, D))
     v = jax.random.normal(ks[2], (B, S, Hkv, D))
-    o = ops.decode_attention(q, k, v, jnp.array([S, S // 2]))
+    o = ops.decode_attention(q, k, v, jnp.array([S, S // 2]),
+                             interpret=interpret)
     print("pallas decode_attention output:", o.shape, "finite:",
           bool(jnp.isfinite(o).all()))
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

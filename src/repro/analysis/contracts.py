@@ -30,6 +30,7 @@ import dataclasses
 from typing import Callable, Optional
 
 import jax
+import jax.extend.core as jex_core
 import numpy as np
 
 _BAD_DTYPES = ("float64", "complex128")
@@ -62,9 +63,7 @@ def iter_eqns(jaxpr):
 
 
 def _subjaxprs(value):
-    import jax.core as jcore
-    kinds = (jcore.Jaxpr, jcore.ClosedJaxpr)
-    if isinstance(value, kinds):
+    if isinstance(value, (jex_core.Jaxpr, jex_core.ClosedJaxpr)):
         yield value
     elif isinstance(value, (tuple, list)):
         for v in value:

@@ -80,14 +80,6 @@ def _n_pods(mesh) -> int:
                                  and "pod" in mesh.axis_names) else 1
 
 
-# Old/new-API shard_map shim now lives in launch/mesh.py (shared with the
-# sharded Track-A round engine). Note the old-API branch only keeps THIS
-# module importable/buildable on old jax — full mesh execution also needs
-# the new ambient-mesh shard_map inside the model stack (models/model.py,
-# models/moe.py), which is why the mesh tests skip on old jax.
-from repro.launch.mesh import shard_map_compat as _shard_map  # noqa: E402
-
-
 def init_state(params, dcfg: DistConfig, mesh=None) -> TrainState:
     np_ = _n_pods(mesh)
 
@@ -248,13 +240,13 @@ def make_train_step(cfg: ModelConfig, dcfg: DistConfig, mesh):
 
             rep = lambda t: jax.tree.map(lambda _: P(), t)
             podded = lambda t: jax.tree.map(lambda _: P("pod"), t)
-            agg, w_fin, new_ef, loss = _shard_map(
-                per_pod, mesh,
+            agg, w_fin, new_ef, loss = jax.shard_map(
+                per_pod, mesh=mesh,
                 in_specs=(rep(state.params), podded(state.prev_params),
                           podded(state.ef), podded(batch), P(), P()),
                 out_specs=(rep(state.params), podded(state.prev_params),
                            podded(state.ef), P()),
-                axis_names={"pod"},
+                axis_names={"pod"}, check_vma=False,
             )(state.params, state.prev_params, state.ef, batch,
               state.theta_d, state.theta_u)
         else:

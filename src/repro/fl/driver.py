@@ -227,6 +227,20 @@ class RoundPkg:
     n_forced: int = 0                 # cohort shortfall force-woken
 
 
+def stratified_cohort(rng: np.random.Generator, n_clients: int, n_part: int,
+                      n_shards: int) -> np.ndarray:
+    """The always-available cohort draw: uniform over all clients on one
+    shard; with ``n_shards > 1``, ``n_part / n_shards`` clients drawn from
+    each shard's contiguous block of client ids, so every device owns its
+    participants' pool rows."""
+    if n_shards <= 1:
+        return rng.choice(n_clients, n_part, replace=False)
+    rows, ps = n_clients // n_shards, n_part // n_shards
+    return np.concatenate([
+        rng.choice(np.arange(s * rows, (s + 1) * rows), ps, replace=False)
+        for s in range(n_shards)])
+
+
 # ---------------------------------------------------------------------------
 # The simulator: orchestration + accounting
 # ---------------------------------------------------------------------------
@@ -436,13 +450,7 @@ class Simulator:
         the avail_log reports."""
         n, d = self.cfg.n_clients, self.n_dev
         if not self._avail_on:
-            if d <= 1:
-                return rng.choice(n, self.n_part, replace=False), n, 0
-            rows, ps = n // d, self.n_part // d
-            return np.concatenate([
-                rng.choice(np.arange(s * rows, (s + 1) * rows), ps,
-                           replace=False)
-                for s in range(d)]), n, 0
+            return stratified_cohort(rng, n, self.n_part, d), n, 0
         mask = AV.eligible_mask(self.cfg.availability, self.cfg.seed, t, n,
                                 self._avail_phases)
         el = np.flatnonzero(mask)

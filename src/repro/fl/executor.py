@@ -365,8 +365,12 @@ class RoundExecutor:
             self._round_step = jax.jit(round_step, donate_argnums=(0, 1, 2))
             return
 
-        def shard_body(global_f, g_cdf, g_max, buf, ef_buf, parts, pmask,
+        def shard_body(global_f, buf, ef_buf, parts, pmask,
                        xs, ys, ws, ims, lr, theta_d, theta_u, seed):
+            # one global-model histogram per round, computed by every shard
+            # on its replicated copy (a Pallas kernel cannot be partitioned
+            # automatically, so it runs inside the shard_map)
+            g_cdf, g_max = C.fused_histogram_cdf(global_f, backend)
             # global slot → shard-local pool row; the segment size comes
             # from the block-local pool shape, so pool growth (a new jit
             # trace) needs no rebuild. Padding (= capacity) stays out of
@@ -381,25 +385,15 @@ class RoundExecutor:
             new_global = global_f - up_sum / jnp.maximum(cnt, 1.0)
             return new_global, buf, ef_buf, db, ub, gn
 
-        sharded = MESH.shard_map_compat(
-            shard_body, self.mesh,
-            in_specs=(P(), P(), P(), P("data", None), P("data", None),
+        sharded = jax.shard_map(
+            shard_body, mesh=self.mesh,
+            in_specs=(P(), P("data", None), P("data", None),
                       P("data"), P("data"), P("data"), P("data"), P("data"),
                       P("data"), P(), P("data"), P("data"), P()),
             out_specs=(P(), P("data", None), P("data", None), P("data"),
                        P("data"), P("data")),
-            axis_names={"data"})
-
-        def round_step_sharded(global_f, pool, ef_buf, parts, pmask,
-                               xs, ys, ws, ims, lr, theta_d, theta_u, seed):
-            # one global-model histogram per round, replicated into shards
-            g_cdf, g_max = C.fused_histogram_cdf(global_f, backend)
-            return sharded(global_f, g_cdf, g_max, pool, ef_buf, parts,
-                           pmask, xs, ys, ws, ims, lr, theta_d, theta_u,
-                           seed)
-
-        self._round_step = jax.jit(round_step_sharded,
-                                   donate_argnums=(0, 1, 2))
+            axis_names={"data"}, check_vma=False)
+        self._round_step = jax.jit(sharded, donate_argnums=(0, 1, 2))
 
     def _build_ragged(self, participant_round):
         """The per-shape tier-chunk step (jax.jit caches one executable per
@@ -445,8 +439,8 @@ class RoundExecutor:
                 # "data"-sharded accumulator; the finalizer reduces them
                 return b, e, u[None], db, ub, gn
 
-            sm = MESH.shard_map_compat(
-                shard_body, self.mesh,
+            sm = jax.shard_map(
+                shard_body, mesh=self.mesh,
                 in_specs=(P("data", None), P("data", None), P("data", None),
                           P(), P(), P(), P("data"), P("data"), P("data"),
                           P("data"), P("data"), P("data"), P(), P("data"),
@@ -454,7 +448,7 @@ class RoundExecutor:
                 out_specs=(P("data", None), P("data", None),
                            P("data", None), P("data"), P("data"),
                            P("data")),
-                axis_names={"data"})
+                axis_names={"data"}, check_vma=False)
             self._tier_chunk = jax.jit(sm, donate_argnums=(0, 1, 2))
 
         def tier_chunk_defer(buf, ef_buf, global_f, g_cdf, g_max, parts_l,
@@ -486,8 +480,15 @@ class RoundExecutor:
         self._tier_chunk_defer = jax.jit(tier_chunk_defer,
                                          donate_argnums=(0, 1))
 
-        self._hist = jax.jit(
-            lambda g: C.fused_histogram_cdf(g, backend))
+        def hist(g):
+            return C.fused_histogram_cdf(g, backend)
+
+        if self.mesh is not None:
+            # the replicated global vector: every shard computes the same
+            # histogram (a Pallas kernel is not partitioned automatically)
+            hist = jax.shard_map(hist, mesh=self.mesh, in_specs=P(),
+                                 out_specs=(P(), P()), check_vma=False)
+        self._hist = jax.jit(hist)
 
         def finalize(global_f, up_sum, cnt):
             total = up_sum if up_sum.ndim == 1 else jnp.sum(up_sum, axis=0)

@@ -67,7 +67,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, out_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret", "kv_block"))
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                     length: jax.Array, interpret: bool = True,
+                     length: jax.Array, *, interpret: bool,
                      kv_block: int = KV_BLOCK) -> jax.Array:
     """q: [B,H,D]; k/v: [B,S,Hkv,D]; length: [B] valid cache length."""
     b, h, d = q.shape

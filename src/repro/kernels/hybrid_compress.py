@@ -1,11 +1,13 @@
 """Pallas TPU kernel: fused hybrid compress pass (paper Fig. 3 sender side).
 
-One HBM read of the tensor produces, per VMEM tile:
+One HBM read of the tensor produces, per ``(rows, 128)`` block of the
+lane-dense layout (`kernels.tiling`):
   * kept values (full precision where |x| ≥ thr, else 0)
   * int8 sign plane (±1 where compressed, 0 where kept)
-  * per-block partials (count, Σ|x|, max|x| over the compressed set)
-The tiny [n_blocks, 3] partials are folded into the (mean_abs, max_abs)
-scalars by XLA — replacing five separate elementwise+reduce HLO passes.
+  * per-lane partials over the compressed set — count, Σ|x|, max|x| — in
+    rows 0–2 of one ``(8, 128)`` f32 tile per block
+The partials are folded into the (count, sum_abs, max_abs) scalars by XLA —
+replacing five separate elementwise+reduce HLO passes.
 """
 from __future__ import annotations
 
@@ -15,60 +17,65 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK = 8 * 128
+from repro.kernels import tiling as T
+
+_PART_ROWS = 8           # one f32 tile of partials per block
 
 
 def _compress_kernel(x_ref, thr_ref, kept_ref, sign_ref, part_ref):
-    x = x_ref[...].astype(jnp.float32)               # [1, BLOCK]
-    thr = thr_ref[0, 0]
+    x = x_ref[...]                                   # [R, 128] f32
     absx = jnp.abs(x)
-    mask = absx < thr
-    kept_ref[...] = jnp.where(mask, 0.0, x).astype(kept_ref.dtype)
+    mask = absx < thr_ref[...]
+    kept_ref[...] = jnp.where(mask, 0.0, x)
     sign_ref[...] = jnp.where(mask, jnp.sign(x), 0.0).astype(jnp.int8)
-    cnt = jnp.sum(mask.astype(jnp.float32))
-    ssum = jnp.sum(jnp.where(mask, absx, 0.0))
-    smax = jnp.max(jnp.where(mask, absx, 0.0))
-    part_ref[...] = jnp.stack([cnt, ssum, smax]).reshape(1, 3)
+    comp = jnp.where(mask, absx, 0.0)
+    cnt = jnp.sum(mask.astype(jnp.float32), axis=0, keepdims=True)
+    ssum = jnp.sum(comp, axis=0, keepdims=True)
+    smax = jnp.max(comp, axis=0, keepdims=True)
+    row = jax.lax.broadcasted_iota(jnp.int32, (_PART_ROWS, T.LANES), 0)
+    part_ref[...] = jnp.where(row == 0, cnt,
+                              jnp.where(row == 1, ssum,
+                                        jnp.where(row == 2, smax, 0.0)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def hybrid_compress(x: jax.Array, thr: jax.Array,
-                    interpret: bool | None = None):
+def hybrid_compress(x: jax.Array, thr: jax.Array, *, interpret: bool):
     """Returns (kept, sign_i8, count, sum_abs, max_abs) — see ref.hybrid_compress."""
-    from repro.kernels.topk_threshold import _resolve_interpret
-    interpret = _resolve_interpret(interpret)
     shape, dtype = x.shape, x.dtype
-    flat = x.reshape(-1)
+    flat = x.reshape(-1).astype(jnp.float32)
     n = flat.shape[0]
-    n_blocks = -(-n // BLOCK)
-    pad = n_blocks * BLOCK - n
+    rows = T.block_rows(n)
     # Pad with +inf so padding is never "compressed" (|inf| ≥ thr always).
-    flat = jnp.pad(flat.astype(jnp.float32), (0, pad),
-                   constant_values=jnp.inf).reshape(n_blocks, BLOCK)
+    tiles = T.to_tiles(flat, rows, jnp.inf)
+    n_blocks = tiles.shape[0] // rows
 
     kept, sign, part = pl.pallas_call(
         _compress_kernel,
         grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
+            pl.BlockSpec((rows, T.LANES), lambda i: (i, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1, 3), lambda i: (i, 0)),
+            pl.BlockSpec((rows, T.LANES), lambda i: (i, 0)),
+            pl.BlockSpec((rows, T.LANES), lambda i: (i, 0)),
+            pl.BlockSpec((_PART_ROWS, T.LANES), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32),
-            jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.int8),
-            jax.ShapeDtypeStruct((n_blocks, 3), jnp.float32),
+            jax.ShapeDtypeStruct(tiles.shape, jnp.float32),
+            jax.ShapeDtypeStruct(tiles.shape, jnp.int8),
+            jax.ShapeDtypeStruct((n_blocks * _PART_ROWS, T.LANES),
+                                 jnp.float32),
         ],
         interpret=interpret,
-    )(flat, thr.astype(jnp.float32).reshape(1, 1))
+        name="hybrid_compress",
+    )(tiles, thr.astype(jnp.float32).reshape(1, 1))
 
-    kept = kept.reshape(-1)[:n].reshape(shape).astype(dtype)
-    sign = sign.reshape(-1)[:n].reshape(shape)
-    count = jnp.sum(part[:, 0]).astype(jnp.int32)
+    kept = T.from_tiles(kept, n).reshape(shape).astype(dtype)
+    sign = T.from_tiles(sign, n).reshape(shape)
+    part = part.reshape(n_blocks, _PART_ROWS, T.LANES)
+    # per-lane counts are exact in f32 (each ≤ rows); sum them as int32
+    count = jnp.sum(part[:, 0].astype(jnp.int32))
     sum_abs = jnp.sum(part[:, 1])
     max_abs = jnp.max(part[:, 2])
     return kept, sign, count, sum_abs, max_abs

@@ -7,11 +7,8 @@ import numpy as np
 
 
 def _mesh(shape, axes):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:        # older jax: no explicit axis types
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -99,22 +96,3 @@ def fetch_global(arr):
         return np.asarray(arr)
     from jax.experimental import multihost_utils
     return np.asarray(multihost_utils.process_allgather(arr, tiled=True))
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, axis_names):
-    """Partial-manual shard_map across old/new jax APIs.
-
-    New jax exposes ``jax.shard_map(..., axis_names=…, check_vma=…)``; older
-    releases spell the same thing ``jax.experimental.shard_map.shard_map``
-    with the *complement* ``auto=`` set and ``check_rep=``. Shared by the
-    Track-B pod reduction (fl/distributed.py) and the sharded Track-A round
-    engine (fl/simulation.py).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - set(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)

@@ -126,8 +126,7 @@ def test_no_f64_contract_flags_wide_dtypes():
     ok = contracts.check_no_f64(
         jax.make_jaxpr(lambda v: v * 2)(jnp.ones((4,), jnp.float32)), "ok")
     assert ok.ok
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         wide = jax.make_jaxpr(lambda v: v * 2)(np.ones((4,), np.float64))
     bad = contracts.check_no_f64(wide, "bad")
     assert not bad.ok and "float64" in bad.detail

@@ -6,6 +6,7 @@ compression — with axis sizes 1). A subprocess test exercises a real
 device count at first init, so it must be a fresh interpreter).
 """
 import dataclasses
+import os
 import subprocess
 import sys
 import textwrap
@@ -19,17 +20,6 @@ import repro.configs as configs
 from repro.fl import distributed as D
 from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
-
-
-# the mesh-based tests drive model-internal jax.shard_map(ambient-mesh) calls
-# that only exist in newer jax; on older releases they skip (the meshless
-# cohort-round tests below still cover the full Caesar compression path)
-NEW_SHARD_MAP = hasattr(jax, "shard_map")
-
-
-def _mesh_ctx(mesh):
-    """jax.set_mesh on new jax; the Mesh context manager on older releases."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
 
 
 def _smoke_setup(arch="qwen1p5_4b", tau=2):
@@ -96,14 +86,12 @@ def test_error_feedback_accumulates():
     assert ef_norm > 0  # dropped 90% of delta went into the EF buffer
 
 
-@pytest.mark.skipif(not NEW_SHARD_MAP,
-                    reason="needs jax.shard_map ambient-mesh API")
 def test_local_mesh_train_step():
     """Same step under a (1,1) mesh exercises shard_map/spec code paths."""
     mesh = make_local_mesh()
     cfg, params, batch = _smoke_setup()
     dcfg = D.DistConfig()
-    with _mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         state = D.init_state(params, dcfg, mesh)
         step = D.make_train_step(cfg, dcfg, mesh)
         state2, m = jax.jit(step)(state, batch)
@@ -118,7 +106,9 @@ _SUBPROC = textwrap.dedent("""
     from repro.fl import distributed as D
     from repro.models import model as M
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    # Auto axes, as every production mesh in launch/mesh.py is built
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     cfg = dataclasses.replace(configs.get("qwen1p5_4b").smoke(),
                               local_iters=1, d_model=64, n_heads=2,
                               n_kv_heads=2, d_head=32, vocab=128)
@@ -126,8 +116,7 @@ _SUBPROC = textwrap.dedent("""
     toks = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, cfg.vocab)
     batch = {"tokens": toks, "labels": toks}
     dcfg = D.DistConfig(theta_d=0.3, theta_u=0.4)
-    mesh_ctx = (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh)
-    with mesh_ctx:
+    with jax.set_mesh(mesh):
         state = D.init_state(params, dcfg, mesh)
         step = D.make_train_step(cfg, dcfg, mesh)
         state2, m = jax.jit(step)(state, batch)
@@ -145,14 +134,16 @@ _SUBPROC = textwrap.dedent("""
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(not NEW_SHARD_MAP,
-                    reason="needs jax.shard_map ambient-mesh API")
 def test_multipod_execution_subprocess():
-    """Real 2-pod execution (8 host devices): pods act as distinct clients."""
+    """Real 2-pod execution (8 host devices): pods act as distinct clients.
+    The child is pinned to the CPU backend: with a TPU runtime installed it
+    would otherwise reach for the accelerator instead of the 8 forced host
+    devices."""
     r = subprocess.run([sys.executable, "-c", _SUBPROC], capture_output=True,
                        text=True, timeout=600,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                            "HOME": os.path.expanduser("~"),
+                            "JAX_PLATFORMS": "cpu"})
     assert "MULTIPOD_OK" in r.stdout, r.stdout + r.stderr
 
 
