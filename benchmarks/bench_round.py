@@ -13,7 +13,8 @@ Measures, on a CPU-budget 100-client/20-round HAR config:
     same seeds (trajectory-parity evidence).
 
 Per-round medians exclude round 1 (the jit compile, reported separately as
-History.compile_s) via the warmup drop in `_median_steady`.
+``round1_s``, ``History.wall_per_round[0]``) via the warmup drop in
+`_median_steady`.
 
 The default uses τ=1 local steps so the measurement isolates the round
 *engine* (the local-SGD math is line-for-line identical in both engines and
@@ -264,7 +265,7 @@ def bench_engines(tau: int, n_clients: int, rounds: int) -> dict:
         "pipeline_speedup": sync_ms / fused_ms,
         "seed_e2e_s": seed_e2e,
         "fused_e2e_s": fused_e2e,
-        "compile_s": h.compile_s,
+        "round1_s": h.wall_per_round[0],
         "seed_final_acc": seed_acc,
         "fused_final_acc": h.accuracy[-1] if h.accuracy else float("nan"),
         "pipelined_equals_sync": h.accuracy == h_sync.accuracy,
@@ -319,7 +320,7 @@ def bench_ragged(tau: int, n_clients: int, rounds: int,
         "masked_e2e_s": masked_e2e,
         "ragged_e2e_s": ragged_e2e,
         "ragged_cold_e2e_s": ragged_cold_e2e,   # includes tier-shape compiles
-        "compile_s": h_r.compile_s,
+        "round1_s": h_r.wall_per_round[0],
         "work_fraction": tel["work_fraction"],
         "tier_occupancy": tel["tier_occupancy"],
         "compiled_tier_shapes": tel["compiled_tier_shapes"],

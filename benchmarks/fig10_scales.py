@@ -136,7 +136,7 @@ def run_point(n_clients: int, chunk_size, rounds: int,
         "ragged": ragged, "buffer_dtype": buffer_dtype,
         "rounds": rounds, "n_params": sim.n_params,
         "s_per_round": median_warm(h),
-        "compile_s": h.compile_s,
+        "round1_s": h.wall_per_round[0],
         # plan-shaped execution telemetry (DESIGN.md §8): per-tier
         # participant counts, jit-cache size vs its lattice bound, and the
         # plan-shaped fraction of the masked engine's FLOPs

@@ -178,7 +178,7 @@ def _run_sim(sim: Simulator, label: str):
           f"{hist.traffic_bits}")
     warm = hist.wall_per_round[1:]
     print(f"  {label}: info: run {wall:.1f} s, round 1 (with compiles) "
-          f"{hist.compile_s:.1f} s"
+          f"{hist.wall_per_round[0]:.1f} s"
           + (f", later rounds median {statistics.median(warm):.3f} s"
              if warm else ""))
     return hist, g

@@ -13,7 +13,11 @@ the loop async). The rule runs a small intra-function taint pass:
   / ``x.item()`` over a tainted expression **inside a for/while loop**.
   A sink also *untaints* its result: the documented once-per-round
   accounting sync (driver.run) reads everything afterwards from host
-  arrays, which is exactly the pattern to keep.
+  arrays, which is exactly the pattern to keep. A tainted keyword
+  argument of a host span (``span(...)`` or its ``.set_metadata(...)``,
+  ``repro.fl.spans``) is a sink too: the profiler converts it to a host
+  number only while a trace runs, so it would sync the traced round and
+  no other.
 
 Deliberate syncs (the accounting point, eval boundaries, legacy-parity
 benchmarks) carry ``# repro: noqa=REP006`` with a justification.
@@ -32,6 +36,8 @@ _TAINT_SOURCES = {"step", "step_ragged", "step_ragged_deferred",
                   "_to_f32", "_round_vmapped", "apply_fn"}
 _SINK_FUNCS = {"float", "int"}
 _NP_SINKS = {"asarray", "array"}
+# host spans: every keyword argument becomes a trace stat
+_SPAN_SINKS = {"span", "set_metadata"}
 
 
 def _is_source_call(node: ast.Call) -> bool:
@@ -39,6 +45,13 @@ def _is_source_call(node: ast.Call) -> bool:
     if parts and parts[0] in ("jnp", "jax"):
         return True
     return terminal_name(node.func) in _TAINT_SOURCES
+
+
+def _span_probes(node: ast.Call) -> list:
+    """The keyword arguments of a host span call (``[]`` otherwise)."""
+    if terminal_name(node.func) not in _SPAN_SINKS:
+        return []
+    return [kw.value for kw in node.keywords]
 
 
 def _sink_kind(node: ast.Call) -> str:
@@ -118,6 +131,15 @@ class REP006(Rule):
             for stmt in stmts:
                 for node in own_nodes(stmt):
                     if not isinstance(node, ast.Call):
+                        continue
+                    if in_loop(node.lineno) and any(
+                            taint.expr_tainted(p)
+                            for p in _span_probes(node)):
+                        yield self.diag(
+                            src, node,
+                            "span argument derived from a device value "
+                            "inside the round loop syncs only while "
+                            "tracing — pass a host count")
                         continue
                     kind = _sink_kind(node)
                     if not kind or not in_loop(node.lineno):

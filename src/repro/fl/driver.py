@@ -57,6 +57,7 @@ from repro.fl import wire as W
 from repro.fl.capability import CapabilityModel
 from repro.fl.executor import RoundExecutor, TierGroup
 from repro.fl.planner import RoundPlanner
+from repro.fl.spans import span
 from repro.fl.state import ClientStateStore
 from repro.launch import mesh as MESH
 from repro.models import paper_models as PM
@@ -172,22 +173,18 @@ class SimConfig:
 class History:
     """Eval-aligned series: every list below has one entry per eval round
     (``rounds[i]`` is the round number of entry i). ``waiting`` is a RUNNING
-    MEAN over all rounds simulated so far; ``wall`` is the running WARM mean
-    — round 1 (which folds the one-time XLA compile into its wall time) is
-    excluded and reported separately as ``compile_s``. Per-round raw samples
-    (round 1 included) live in the ``*_per_round`` lists. Under the ragged
-    engine, later rounds that first touch a new tier shape also pay a
-    one-time compile inside their wall sample — medians, not means, are the
-    robust per-round statistic."""
+    MEAN over all rounds simulated so far. Per-round raw samples live in the
+    ``*_per_round`` lists; ``wall_per_round[0]`` carries the one-time XLA
+    compile, and under the ragged engine later rounds that first touch a
+    new tier shape also pay a compile inside their wall sample — the median
+    after round 1, not a mean, is the robust per-round statistic."""
     rounds: list = dataclasses.field(default_factory=list)
     sim_time: list = dataclasses.field(default_factory=list)      # cumulative s
     traffic_bits: list = dataclasses.field(default_factory=list)  # cumulative
     accuracy: list = dataclasses.field(default_factory=list)
     waiting: list = dataclasses.field(default_factory=list)       # running mean s
-    wall: list = dataclasses.field(default_factory=list)          # warm mean s
     waiting_per_round: list = dataclasses.field(default_factory=list)
     wall_per_round: list = dataclasses.field(default_factory=list)
-    compile_s: float = 0.0     # round-1 wall (jit compile + first dispatch)
     # wire engine only: cumulative SERIALIZED bytes×8 actually sent
     # (headers, bitpacked indices, CRC, retransmissions) — the measured
     # counterpart of the modeled ``traffic_bits``; empty under "inproc"
@@ -644,30 +641,36 @@ class Simulator:
         pipelined): draw → capability snapshot → [Caesar: plan + state
         advance] → batch gather (tier-shaped when the plan is known,
         cap-shaped otherwise). Never touches the state store."""
-        rng = self._round_rng(t)
-        parts, n_el, n_forced = self._select_participants(rng, t)
-        idx = self._draw_indices(rng, parts)
-        mu, bw_d, bw_u = self.cap.snapshot(t)
-        if self.planner.is_caesar and self.cfg.ragged:
-            # planning inside the producer is what makes the TIER-shaped
-            # gather possible; without that payoff (masked mode) the plan
-            # stays on the main thread — its (tiny) jitted math would only
-            # contend with the in-flight device step
-            plan = self.planner.plan(t, parts, mu, bw_d, bw_u)
-            fplan = self._plan_faults(t, parts, plan, mu, bw_d, bw_u)
-            # failed rounds never advance their clients' participation
-            # record: a dropped client's next round must resync exactly as
-            # if it had not participated (its pool row rolls back too)
-            self.planner.advance(
-                t, parts if fplan is None else parts[fplan.record])
-            tiers = self._tiers_from_idx(idx, plan[2], plan[3], bufs)
-            return RoundPkg(parts, mu, bw_d, bw_u, plan=plan, tiers=tiers,
-                            fplan=fplan, n_eligible=n_el, n_forced=n_forced)
-        if "cap" not in bufs:
-            bufs["cap"] = self._alloc_batch_buffers(self.n_part)
-        xs, ys = self._gather_cap(idx, bufs["cap"])
-        return RoundPkg(parts, mu, bw_d, bw_u, xs=xs, ys=ys,
-                        n_eligible=n_el, n_forced=n_forced)
+        with span("prefetch", round=t):
+            rng = self._round_rng(t)
+            parts, n_el, n_forced = self._select_participants(rng, t)
+            idx = self._draw_indices(rng, parts)
+            mu, bw_d, bw_u = self.cap.snapshot(t)
+            if self.planner.is_caesar and self.cfg.ragged:
+                # planning inside the producer is what makes the TIER-shaped
+                # gather possible; without that payoff (masked mode) the
+                # plan stays on the main thread — its (tiny) jitted math
+                # would only contend with the in-flight device step
+                with span("prefetch.plan", round=t):
+                    plan = self.planner.plan(t, parts, mu, bw_d, bw_u)
+                    fplan = self._plan_faults(t, parts, plan, mu, bw_d, bw_u)
+                    # failed rounds never advance their clients'
+                    # participation record: a dropped client's next round
+                    # must resync exactly as if it had not participated
+                    # (its pool row rolls back too)
+                    self.planner.advance(
+                        t, parts if fplan is None else parts[fplan.record])
+                with span("prefetch.gather", round=t):
+                    tiers = self._tiers_from_idx(idx, plan[2], plan[3], bufs)
+                return RoundPkg(parts, mu, bw_d, bw_u, plan=plan,
+                                tiers=tiers, fplan=fplan, n_eligible=n_el,
+                                n_forced=n_forced)
+            if "cap" not in bufs:
+                bufs["cap"] = self._alloc_batch_buffers(self.n_part)
+            with span("prefetch.gather", round=t):
+                xs, ys = self._gather_cap(idx, bufs["cap"])
+            return RoundPkg(parts, mu, bw_d, bw_u, xs=xs, ys=ys,
+                            n_eligible=n_el, n_forced=n_forced)
 
     # ------------------------------------------------------------------
     # The wire-boundary round (DESIGN.md §11): deferred tier-chunk step →
@@ -699,120 +702,125 @@ class Simulator:
         sent = []        # pos (parts order) in send order
         retained = {}    # pos -> clean payload, for the retry-once path
         rows = []        # (pos, idx [k], vals [k]) in chunk-stream order
-        for pos_c, slots, c, ups in chunks:
-            ups_np = np.asarray(ups)
-            for row_i, pos in zip(slots, pos_c):
-                pos = int(pos)
-                if fp.status[pos] == F.DROP:
-                    continue
-                row = ups_np[row_i]
-                idx = np.flatnonzero(row)
-                rows.append((pos, idx, row[idx]))
-        alie = None
-        if cfg.faults.attack == "alie" and bool(fp.byz.any()):
-            hsum = np.zeros(self.n_params, np.float64)
-            hsq = np.zeros(self.n_params, np.float64)
-            hn, hks, hnorms = 0, [], []
+        with span("wire.collect", round=t):
+            for pos_c, slots, c, ups in chunks:
+                ups_np = np.asarray(ups)
+                for row_i, pos in zip(slots, pos_c):
+                    pos = int(pos)
+                    if fp.status[pos] == F.DROP:
+                        continue
+                    row = ups_np[row_i]
+                    idx = np.flatnonzero(row)
+                    rows.append((pos, idx, row[idx]))
+        with span("wire.encode", round=t):
+            alie = None
+            if cfg.faults.attack == "alie" and bool(fp.byz.any()):
+                hsum = np.zeros(self.n_params, np.float64)
+                hsq = np.zeros(self.n_params, np.float64)
+                hn, hks, hnorms = 0, [], []
+                for pos, idx, vals in rows:
+                    if fp.byz[pos]:
+                        continue
+                    v64 = vals.astype(np.float64)
+                    hsum[idx] += v64
+                    hsq[idx] += v64 * v64
+                    hn += 1
+                    hks.append(len(idx))
+                    hnorms.append(float(np.linalg.norm(v64)))
+                if hn:
+                    alie = F.alie_payload(cfg.faults, hsum, hsq, hn,
+                                          int(np.median(hks)),
+                                          float(np.median(hnorms)))
             for pos, idx, vals in rows:
                 if fp.byz[pos]:
-                    continue
-                v64 = vals.astype(np.float64)
-                hsum[idx] += v64
-                hsq[idx] += v64 * v64
-                hn += 1
-                hks.append(len(idx))
-                hnorms.append(float(np.linalg.norm(v64)))
-            if hn:
-                alie = F.alie_payload(cfg.faults, hsum, hsq, hn,
-                                      int(np.median(hks)),
-                                      float(np.median(hnorms)))
-        for pos, idx, vals in rows:
-            if fp.byz[pos]:
-                idx, vals = F.attack_payload(
-                    cfg.faults, cfg.seed, t, int(parts[pos]), idx, vals,
-                    self.n_params, alie=alie)
-            payload = W.encode_upload(
-                idx, vals, client=int(parts[pos]), round_=t,
-                n_params=self.n_params,
-                value_dtype=cfg.wire_value_dtype)
-            retained[pos] = payload
-            wire_bytes += len(payload)
-            if fp.corrupt_first[pos]:
-                payload = F.flip_bit(payload, cfg.seed, t,
-                                     int(parts[pos]), salt=0)
-            tr.send(payload)
-            sent.append(pos)
-        payloads = (tr.drain(len(sent)) if cfg.wire == "queue"
-                    else tr.drain())
+                    idx, vals = F.attack_payload(
+                        cfg.faults, cfg.seed, t, int(parts[pos]), idx, vals,
+                        self.n_params, alie=alie)
+                payload = W.encode_upload(
+                    idx, vals, client=int(parts[pos]), round_=t,
+                    n_params=self.n_params,
+                    value_dtype=cfg.wire_value_dtype)
+                retained[pos] = payload
+                wire_bytes += len(payload)
+                if fp.corrupt_first[pos]:
+                    payload = F.flip_bit(payload, cfg.seed, t,
+                                         int(parts[pos]), salt=0)
+                tr.send(payload)
+                sent.append(pos)
+            payloads = (tr.drain(len(sent)) if cfg.wire == "queue"
+                        else tr.drain())
 
         # -- server side: decode + CRC check, retry-once, deadline sort --
         accepted = []        # (pos, WireUpload) folded THIS round
         deferred_next = []   # (client, WireUpload) arriving next round
         n_crc_drop = 0
-        for pos, payload in zip(sent, payloads):
-            try:
-                u = W.decode_upload(payload)
-            except W.WireCRCError:
-                # retry-once: the client retransmits its retained payload
-                # (priced as real traffic); a corrupted retry drops it
-                p2 = retained[pos]
-                wire_bytes += len(p2)
-                resent[pos] = True
-                if fp.status[pos] == F.CORRUPT_DROP:
-                    p2 = F.flip_bit(p2, cfg.seed, t, int(parts[pos]),
-                                    salt=1)
+        with span("wire.decode", round=t):
+            for pos, payload in zip(sent, payloads):
                 try:
-                    u = W.decode_upload(p2)
+                    u = W.decode_upload(payload)
                 except W.WireCRCError:
-                    n_crc_drop += 1
+                    # retry-once: the client retransmits its retained
+                    # payload (priced as real traffic); a corrupted retry
+                    # drops it
+                    p2 = retained[pos]
+                    wire_bytes += len(p2)
+                    resent[pos] = True
+                    if fp.status[pos] == F.CORRUPT_DROP:
+                        p2 = F.flip_bit(p2, cfg.seed, t, int(parts[pos]),
+                                        salt=1)
+                    try:
+                        u = W.decode_upload(p2)
+                    except W.WireCRCError:
+                        n_crc_drop += 1
+                        continue
+                if fp.status[pos] == F.LATE:
+                    if cfg.faults.late_policy == "defer":
+                        deferred_next.append((int(parts[pos]), u))
                     continue
-            if fp.status[pos] == F.LATE:
-                if cfg.faults.late_policy == "defer":
-                    deferred_next.append((int(parts[pos]), u))
-                continue
-            accepted.append((pos, u))
+                accepted.append((pos, u))
         defer_in = self._deferred
         self._deferred = deferred_next
 
         # -- robust aggregate: replay the chunk stream + late arrivals --
-        agg = self._aggregator
-        if agg.needs_norms:
-            norms = np.asarray(
-                [float(np.linalg.norm(u.values)) for _, u in accepted]
-                + [float(np.linalg.norm(u.values)) for _, u in defer_in])
-            sc = agg.scales(norms)
-            w_of = dict(zip([pos for pos, _ in accepted], sc.tolist()))
-            w_defer = sc[len(accepted):].tolist()
-        else:
-            w_of = {pos: 1.0 for pos, _ in accepted}
-            w_defer = [1.0] * len(defer_in)
-        by_pos = dict(accepted)
-        carry = agg.init(self.n_params)
-        cnt = 0
-        for pos_c, slots, c, _ups in chunks:
-            dense = np.zeros((c, self.n_params), np.float32)
-            w = np.zeros(c, np.float32)
-            for row_i, pos in zip(slots, pos_c):
-                u = by_pos.get(int(pos))
-                if u is None:
-                    continue
-                dense[row_i, u.indices] = u.values
-                w[row_i] = w_of[int(pos)]
-                cnt += 1
-            carry = agg.update(carry, dense, w)
-        if defer_in:
-            # deferred arrivals fold after the live chunks, rung-padded so
-            # the jit cache sees power-of-two shapes only
-            d = len(defer_in)
-            d_pad = 1 << (d - 1).bit_length()
-            dense = np.zeros((d_pad, self.n_params), np.float32)
-            w = np.zeros(d_pad, np.float32)
-            for i, (_cl, u) in enumerate(defer_in):
-                dense[i, u.indices] = u.values
-                w[i] = w_defer[i]
-            carry = agg.update(carry, dense, w)
-            cnt += d
-        new_global = agg.finalize(global_f, carry, cnt)
+        with span("agg.host", round=t):
+            agg = self._aggregator
+            if agg.needs_norms:
+                norms = np.asarray(
+                    [float(np.linalg.norm(u.values)) for _, u in accepted]
+                    + [float(np.linalg.norm(u.values)) for _, u in defer_in])
+                sc = agg.scales(norms)
+                w_of = dict(zip([pos for pos, _ in accepted], sc.tolist()))
+                w_defer = sc[len(accepted):].tolist()
+            else:
+                w_of = {pos: 1.0 for pos, _ in accepted}
+                w_defer = [1.0] * len(defer_in)
+            by_pos = dict(accepted)
+            carry = agg.init(self.n_params)
+            cnt = 0
+            for pos_c, slots, c, _ups in chunks:
+                dense = np.zeros((c, self.n_params), np.float32)
+                w = np.zeros(c, np.float32)
+                for row_i, pos in zip(slots, pos_c):
+                    u = by_pos.get(int(pos))
+                    if u is None:
+                        continue
+                    dense[row_i, u.indices] = u.values
+                    w[row_i] = w_of[int(pos)]
+                    cnt += 1
+                carry = agg.update(carry, dense, w)
+            if defer_in:
+                # deferred arrivals fold after the live chunks, rung-padded
+                # so the jit cache sees power-of-two shapes only
+                d = len(defer_in)
+                d_pad = 1 << (d - 1).bit_length()
+                dense = np.zeros((d_pad, self.n_params), np.float32)
+                w = np.zeros(d_pad, np.float32)
+                for i, (_cl, u) in enumerate(defer_in):
+                    dense[i, u.indices] = u.values
+                    w[i] = w_defer[i]
+                carry = agg.update(carry, dense, w)
+                cnt += d
+            new_global = agg.finalize(global_f, carry, cnt)
 
         self.fault_log.append({
             "round": t, "parts": parts.copy(),
@@ -888,124 +896,133 @@ class Simulator:
         try:
             pending = pool.submit(prefetch, start_round) if pool else None
             for t in range(start_round, cfg.rounds + 1):
-                wall0 = time.perf_counter()
-                if pool:
-                    pkg = pending.result()
-                    if t < cfg.rounds:
-                        pending = pool.submit(prefetch, t + 1)
-                else:
-                    pkg = prefetch(t)
-                parts = pkg.parts
-                mu, bw_d, bw_u = pkg.mu, pkg.bw_d, pkg.bw_u
-                # participant staleness at draw time (δ = t − last recorded
-                # participation; δ = t for first-timers) — the distribution
-                # the download policy keys compression off, logged per
-                # round alongside the availability counts. MAIN thread
-                # only: `_last_part` must advance in round order.
-                stale = t - self._last_part[parts]
-                self.avail_log.append({
-                    "round": t, "n_eligible": int(pkg.n_eligible),
-                    "n_forced": int(pkg.n_forced),
-                    "staleness": AV.staleness_stats(stale)})
-                rec = (parts if pkg.fplan is None
-                       else parts[pkg.fplan.record])
-                self._last_part[rec] = t
-                lr = jnp.float32(SGD.lr_at(cfg.sgd, jnp.float32(t - 1)))
-
-                if pkg.plan is not None:
-                    theta_d, theta_u, batch, taus = pkg.plan
-                else:
-                    theta_d, theta_u, batch, taus = self.planner.plan(
-                        t, parts, mu, bw_d, bw_u)
-                    # participation records advance right after planning
-                    # (masked caesar; the worker never touches the planner
-                    # on this path, so main-thread ordering is the only
-                    # ordering)
-                    self.planner.advance(t, parts)
-                td32 = np.asarray(theta_d, np.float32)
-                tu32 = np.asarray(theta_u, np.float32)
-                wire_bytes = 0
-                if cfg.ragged:
-                    tiers = (pkg.tiers if pkg.tiers is not None else
-                             self._tiers_from_cap(pkg.xs, pkg.ys, batch,
-                                                  taus))
-                    if self._wire_on:
-                        (global_f, down_bits, up_bits, gnorms,
-                         wire_bytes) = self._wire_round(
-                            global_f, store, pkg, tiers, lr, td32, tu32, t)
+                with span("round", round=t):
+                    wall0 = time.perf_counter()
+                    if pool:
+                        with span("round.wait_prefetch", round=t):
+                            pkg = pending.result()
+                        if t < cfg.rounds:
+                            pending = pool.submit(prefetch, t + 1)
                     else:
+                        pkg = prefetch(t)
+                    with span("round.setup", round=t):
+                        parts = pkg.parts
+                        mu, bw_d, bw_u = pkg.mu, pkg.bw_d, pkg.bw_u
+                        # participant staleness at draw time (δ = t − last
+                        # recorded participation; δ = t for first-timers) —
+                        # the distribution the download policy keys
+                        # compression off, logged per round alongside the
+                        # availability counts. MAIN thread only:
+                        # `_last_part` must advance in round order.
+                        stale = t - self._last_part[parts]
+                        self.avail_log.append({
+                            "round": t, "n_eligible": int(pkg.n_eligible),
+                            "n_forced": int(pkg.n_forced),
+                            "staleness": AV.staleness_stats(stale)})
+                        rec = (parts if pkg.fplan is None
+                               else parts[pkg.fplan.record])
+                        self._last_part[rec] = t
+                        lr = jnp.float32(SGD.lr_at(cfg.sgd,
+                                                   jnp.float32(t - 1)))
+
+                        if pkg.plan is not None:
+                            theta_d, theta_u, batch, taus = pkg.plan
+                        else:
+                            with span("round.plan", round=t):
+                                theta_d, theta_u, batch, taus = \
+                                    self.planner.plan(t, parts, mu, bw_d,
+                                                      bw_u)
+                                # participation records advance right
+                                # after planning (masked caesar; the worker
+                                # never touches the planner on this path,
+                                # so main-thread ordering is the only
+                                # ordering)
+                                self.planner.advance(t, parts)
+                        td32 = np.asarray(theta_d, np.float32)
+                        tu32 = np.asarray(theta_u, np.float32)
+                    wire_bytes = 0
+                    if cfg.ragged:
+                        tiers = (pkg.tiers if pkg.tiers is not None else
+                                 self._tiers_from_cap(pkg.xs, pkg.ys, batch,
+                                                      taus))
+                        if self._wire_on:
+                            (global_f, down_bits, up_bits, gnorms,
+                             wire_bytes) = self._wire_round(
+                                global_f, store, pkg, tiers, lr, td32, tu32,
+                                t)
+                        else:
+                            (global_f, down_bits, up_bits,
+                             gnorms) = self.executor.step_ragged(
+                                global_f, store, parts, tiers, lr, td32,
+                                tu32, t=t)
+                    else:
+                        ws, ims = self._batch_masks(batch, taus, b_max, tau)
                         (global_f, down_bits, up_bits,
-                         gnorms) = self.executor.step_ragged(
-                            global_f, store, parts, tiers, lr, td32, tu32,
-                            t=t)
-                else:
-                    ws, ims = self._batch_masks(batch, taus, b_max, tau)
-                    (global_f, down_bits, up_bits,
-                     gnorms) = self.executor.step(
-                        global_f, store, parts, pkg.xs, pkg.ys,
-                        ws, ims, lr, td32, tu32, t=t)
-                self.planner.observe(t, parts, gnorms)
+                         gnorms) = self.executor.step(
+                            global_f, store, parts, pkg.xs, pkg.ys,
+                            ws, ims, lr, td32, tu32, t=t)
+                    self.planner.observe(t, parts, gnorms)
 
-                # --- accounting ---
-                # traffic: actual hybrid/top-k payload bits on the wire.
-                # THE documented per-round sync point: blocking on the step
-                # outputs here is what makes wall_per_round honest
-                down_b = np.asarray(down_bits, np.float64)  # repro: noqa=REP006
-                up_b = np.asarray(up_bits, np.float64)  # repro: noqa=REP006
-                cum_bits += float(down_b.sum() + up_b.sum())
-                # time + barrier waiting: the Eq.-7 θ·Q/β model — the SAME
-                # model optimize_batch_sizes equalizes (core/batchsize.py),
-                # evaluated at the PLANNED (b_i, τ_i) — tier quantization
-                # is an executor-shape concern, invisible to simulated time
-                times = np.asarray(BS.round_times(
-                    np.asarray(theta_d, np.float64),
-                    np.asarray(theta_u, np.float64), q_bits,
-                    bw_d[parts], bw_u[parts],
-                    np.asarray(taus, np.float64),
-                    np.asarray(batch, np.float64), mu[parts]))
-                # under the wire engine a straggler deadline CLOSES the
-                # round early (late uploads discarded or deferred); with no
-                # deadline (inf) this is exactly the legacy barrier
-                close = float(times.max())
-                if pkg.fplan is not None:
-                    close = min(close, float(pkg.fplan.deadline))
-                cum_time += close
-                waiting = float(np.mean(np.maximum(close - times, 0.0)))
-                waiting_sum += waiting
-                wire_bits_cum += wire_bytes * 8.0
-                self._t_done = t
-                hist.waiting_per_round.append(waiting)
-                # the np.asarray conversions above synced on the step
-                # outputs, so this is an honest per-round host wall-clock
-                hist.wall_per_round.append(time.perf_counter() - wall0)
-                if t == 1:
-                    hist.compile_s = hist.wall_per_round[0]
+                    with span("round.account", round=t):
+                        # traffic: actual hybrid/top-k payload bits on the
+                        # wire. THE documented per-round sync point:
+                        # blocking on the step outputs here is what makes
+                        # wall_per_round honest
+                        down_b = np.asarray(down_bits, np.float64)  # repro: noqa=REP006
+                        up_b = np.asarray(up_bits, np.float64)  # repro: noqa=REP006
+                        cum_bits += float(down_b.sum() + up_b.sum())
+                        # time + barrier waiting: the Eq.-7 θ·Q/β model —
+                        # the SAME model optimize_batch_sizes equalizes
+                        # (core/batchsize.py), evaluated at the PLANNED
+                        # (b_i, τ_i) — tier quantization is an
+                        # executor-shape concern, invisible to simulated
+                        # time
+                        times = np.asarray(BS.round_times(
+                            np.asarray(theta_d, np.float64),
+                            np.asarray(theta_u, np.float64), q_bits,
+                            bw_d[parts], bw_u[parts],
+                            np.asarray(taus, np.float64),
+                            np.asarray(batch, np.float64), mu[parts]))
+                        # under the wire engine a straggler deadline CLOSES
+                        # the round early (late uploads discarded or
+                        # deferred); with no deadline (inf) this is exactly
+                        # the legacy barrier
+                        close = float(times.max())
+                        if pkg.fplan is not None:
+                            close = min(close, float(pkg.fplan.deadline))
+                        cum_time += close
+                        waiting = float(np.mean(np.maximum(close - times,
+                                                           0.0)))
+                    waiting_sum += waiting
+                    wire_bits_cum += wire_bytes * 8.0
+                    self._t_done = t
+                    hist.waiting_per_round.append(waiting)
+                    # the np.asarray conversions above synced on the step
+                    # outputs, so this is an honest per-round host
+                    # wall-clock
+                    hist.wall_per_round.append(time.perf_counter() - wall0)
 
-                if t % cfg.eval_every == 0 or t == cfg.rounds:
-                    ne = min(cfg.eval_samples, len(self.data.y_test))
-                    # eval boundary, cadence-limited by cfg.eval_every
-                    acc = float(self._eval(global_f,  # repro: noqa=REP006
-                                           jnp.asarray(self.data.x_test[:ne]),
-                                           jnp.asarray(self.data.y_test[:ne])))
-                    hist.rounds.append(t)
-                    hist.sim_time.append(cum_time)
-                    hist.traffic_bits.append(cum_bits)
-                    hist.accuracy.append(acc)
-                    hist.waiting.append(waiting_sum / t)
-                    if self._wire_on:
-                        hist.wire_bits.append(wire_bits_cum)
-                    # warm mean: round 1 carries the jit compile
-                    # (hist.compile_s); until a warm sample exists, fall
-                    # back to the cold one
-                    warm = hist.wall_per_round[1:] or hist.wall_per_round
-                    hist.wall.append(float(np.mean(warm)))
-                    log(f"[{cfg.scheme}/{cfg.dataset}] round {t:4d} "
-                        f"acc={acc:.4f} time={cum_time:,.0f}s "
-                        f"traffic={cum_bits/8e9:.3f}GB "
-                        f"wait={waiting_sum / t:.1f}s")
-                    if (cfg.target_accuracy is not None
-                            and acc >= cfg.target_accuracy):
-                        break
+                    if t % cfg.eval_every == 0 or t == cfg.rounds:
+                        ne = min(cfg.eval_samples, len(self.data.y_test))
+                        # eval boundary, cadence-limited by cfg.eval_every
+                        with span("round.eval", round=t):
+                            acc = float(self._eval(  # repro: noqa=REP006
+                                global_f, jnp.asarray(self.data.x_test[:ne]),
+                                jnp.asarray(self.data.y_test[:ne])))
+                            hist.rounds.append(t)
+                            hist.sim_time.append(cum_time)
+                            hist.traffic_bits.append(cum_bits)
+                            hist.accuracy.append(acc)
+                            hist.waiting.append(waiting_sum / t)
+                            if self._wire_on:
+                                hist.wire_bits.append(wire_bits_cum)
+                            log(f"[{cfg.scheme}/{cfg.dataset}] round {t:4d} "
+                                f"acc={acc:.4f} time={cum_time:,.0f}s "
+                                f"traffic={cum_bits/8e9:.3f}GB "
+                                f"wait={waiting_sum / t:.1f}s")
+                        if (cfg.target_accuracy is not None
+                                and acc >= cfg.target_accuracy):
+                            break
         finally:
             if pool:
                 pool.shutdown(wait=False, cancel_futures=True)

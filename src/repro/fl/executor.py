@@ -34,6 +34,7 @@ from repro.core import batchsize as BS
 from repro.core import compression as C
 from repro.core import rng as RNG
 from repro.fl.robust import weighted_row_fold
+from repro.fl.spans import span
 from repro.launch import mesh as MESH
 
 BUFFER_DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
@@ -543,7 +544,8 @@ class RoundExecutor:
                 raise ValueError(
                     "sharded mode needs stratified participants "
                     f"({self.p_shard} per shard; got {counts.tolist()})")
-        slots = store.prepare(parts, t)
+        with span("exec.prepare", round=t):
+            slots = store.prepare(parts, t)
         # a client's slot lives in its own shard's segment, so the
         # client-shard order IS the slot-shard order
         return slots, np.argsort(owner, kind="stable")
@@ -557,17 +559,20 @@ class RoundExecutor:
         slots, order = self._resolve_slots(store, parts, t)
         g = lambda a, fill: self._put(self._group(a, order, fill),
                                       P("data"))
-        new_global, new_pool, new_ef, db, ub, gn = self._round_step(
-            global_f, store.pool, store.ef_pool,
-            g(slots, np.int32(store.capacity)),
-            g(np.ones(len(parts), np.float32), np.float32(0.0)),
-            g(xs, xs.dtype.type(0)), g(ys, ys.dtype.type(0)),
-            g(ws, np.float32(0.0)), g(ims, np.float32(0.0)), lr,
-            g(theta_d, np.float32(0.0)), g(theta_u, np.float32(0.0)),
-            jnp.uint32(self._round_seed(t)))
-        store.adopt(new_pool, new_ef)
-        return (new_global, self._ungroup(db, order),
-                self._ungroup(ub, order), self._ungroup(gn, order))
+        with span("exec.dispatch", round=t, rows=self.n_dev * self.p_pad,
+                  valid=len(parts)):
+            new_global, new_pool, new_ef, db, ub, gn = self._round_step(
+                global_f, store.pool, store.ef_pool,
+                g(slots, np.int32(store.capacity)),
+                g(np.ones(len(parts), np.float32), np.float32(0.0)),
+                g(xs, xs.dtype.type(0)), g(ys, ys.dtype.type(0)),
+                g(ws, np.float32(0.0)), g(ims, np.float32(0.0)), lr,
+                g(theta_d, np.float32(0.0)), g(theta_u, np.float32(0.0)),
+                jnp.uint32(self._round_seed(t)))
+            store.adopt(new_pool, new_ef)
+        with span("exec.readback", round=t):
+            return (new_global, self._ungroup(db, order),
+                    self._ungroup(ub, order), self._ungroup(gn, order))
 
     # -- ragged execution ---------------------------------------------------
 
@@ -639,68 +644,74 @@ class RoundExecutor:
         n = len(parts)
         n_params = self.spec.n_params
         slots32, _ = self._resolve_slots(store, parts, t)
-        g_cdf, g_max = self._hist(global_f)
-        if self.mesh is None:
-            up_sum = jnp.zeros(n_params, jnp.float32)
-        else:
-            up_sum = self._put(np.zeros((self.n_dev, n_params), np.float32),
-                               P("data", None))
-        buf, ef = store.pool, store.ef_pool
-        pend = []
-        call_i = 0
-        for tg in tiers:
-            key = (int(tg.b), int(tg.tau))
-            self.tier_occupancy[key] = (self.tier_occupancy.get(key, 0)
-                                        + len(tg.pos))
-            for pos_c, slots, a in self._tier_chunks(
-                    tg, slots32, theta_d, theta_u,
-                    pad_idx=store.capacity,
-                    cap_per_shard=store.cap_per_shard):
-                # count the rows actually executed (the sharded path re-pads
-                # tiers to a cross-shard rung, exceeding the tier's g_pad)
-                self.work_ragged += len(a["parts"]) * tg.tau * tg.b
-                self._shapes_seen.add((len(a["parts"]) // self.n_dev,
-                                       int(tg.tau), int(tg.b)))
-                if self.mesh is None:
-                    # single compiled kernel shared with the wire path:
-                    # tier_chunk_defer + the association-fixed fold — one
-                    # XLA module either way, so wire replay bit-identity
-                    # holds by construction, not by fusion luck
-                    pmask = jnp.asarray(a["pmask"])
-                    buf, ef, ups, db, ub, gn = self._tier_chunk_defer(
-                        buf, ef, global_f, g_cdf, g_max,
-                        jnp.asarray(a["parts"]), pmask,
-                        jnp.asarray(a["xs"]), jnp.asarray(a["ys"]),
-                        jnp.asarray(a["ws"]), jnp.asarray(a["ims"]), lr,
-                        jnp.asarray(a["td"]), jnp.asarray(a["tu"]),
+        with span("exec.dispatch", round=t) as sp:
+            g_cdf, g_max = self._hist(global_f)
+            if self.mesh is None:
+                up_sum = jnp.zeros(n_params, jnp.float32)
+            else:
+                up_sum = self._put(np.zeros((self.n_dev, n_params),
+                                            np.float32), P("data", None))
+            buf, ef = store.pool, store.ef_pool
+            pend = []
+            call_i = rows = 0
+            for tg in tiers:
+                key = (int(tg.b), int(tg.tau))
+                self.tier_occupancy[key] = (self.tier_occupancy.get(key, 0)
+                                            + len(tg.pos))
+                for pos_c, slots, a in self._tier_chunks(
+                        tg, slots32, theta_d, theta_u,
+                        pad_idx=store.capacity,
+                        cap_per_shard=store.cap_per_shard):
+                    # count the rows actually executed (the sharded path
+                    # re-pads tiers to a cross-shard rung, exceeding the
+                    # tier's g_pad)
+                    rows += len(a["parts"])
+                    self.work_ragged += len(a["parts"]) * tg.tau * tg.b
+                    self._shapes_seen.add((len(a["parts"]) // self.n_dev,
+                                           int(tg.tau), int(tg.b)))
+                    if self.mesh is None:
+                        # single compiled kernel shared with the wire path:
+                        # tier_chunk_defer + the association-fixed fold —
+                        # one XLA module either way, so wire replay
+                        # bit-identity holds by construction, not by fusion
+                        # luck
+                        pmask = jnp.asarray(a["pmask"])
+                        buf, ef, ups, db, ub, gn = self._tier_chunk_defer(
+                            buf, ef, global_f, g_cdf, g_max,
+                            jnp.asarray(a["parts"]), pmask,
+                            jnp.asarray(a["xs"]), jnp.asarray(a["ys"]),
+                            jnp.asarray(a["ws"]), jnp.asarray(a["ims"]), lr,
+                            jnp.asarray(a["td"]), jnp.asarray(a["tu"]),
+                            jnp.uint32(self._round_seed(t, call_i)))
+                        up_sum = self._fold(up_sum, ups, pmask)
+                        call_i += 1
+                        pend.append((pos_c, slots, db, ub, gn))
+                        continue
+                    buf, ef, up_sum, db, ub, gn = self._tier_chunk(
+                        buf, ef, up_sum, global_f, g_cdf, g_max,
+                        self._put(a["parts"], P("data")),
+                        self._put(a["pmask"], P("data")),
+                        self._put(a["xs"], P("data")),
+                        self._put(a["ys"], P("data")),
+                        self._put(a["ws"], P("data")),
+                        self._put(a["ims"], P("data")), lr,
+                        self._put(a["td"], P("data")),
+                        self._put(a["tu"], P("data")),
                         jnp.uint32(self._round_seed(t, call_i)))
-                    up_sum = self._fold(up_sum, ups, pmask)
                     call_i += 1
                     pend.append((pos_c, slots, db, ub, gn))
-                    continue
-                buf, ef, up_sum, db, ub, gn = self._tier_chunk(
-                    buf, ef, up_sum, global_f, g_cdf, g_max,
-                    self._put(a["parts"], P("data")),
-                    self._put(a["pmask"], P("data")),
-                    self._put(a["xs"], P("data")),
-                    self._put(a["ys"], P("data")),
-                    self._put(a["ws"], P("data")),
-                    self._put(a["ims"], P("data")), lr,
-                    self._put(a["td"], P("data")),
-                    self._put(a["tu"], P("data")),
-                    jnp.uint32(self._round_seed(t, call_i)))
-                call_i += 1
-                pend.append((pos_c, slots, db, ub, gn))
-        store.adopt(buf, ef)
-        self.work_cap += n * self.tau_cap * self.b_cap
-        new_global = self._finalize(global_f, up_sum, np.float32(n))
+            store.adopt(buf, ef)
+            self.work_cap += n * self.tau_cap * self.b_cap
+            new_global = self._finalize(global_f, up_sum, np.float32(n))
+            sp.set_metadata(rows=rows, valid=n)
         db_o = np.empty(n, np.float32)
         ub_o = np.empty(n, np.float32)
         gn_o = np.empty(n, np.float32)
-        for pos_c, slots, db, ub, gn in pend:
-            db_o[pos_c] = MESH.fetch_global(db)[slots]
-            ub_o[pos_c] = MESH.fetch_global(ub)[slots]
-            gn_o[pos_c] = MESH.fetch_global(gn)[slots]
+        with span("exec.readback", round=t):
+            for pos_c, slots, db, ub, gn in pend:
+                db_o[pos_c] = MESH.fetch_global(db)[slots]
+                ub_o[pos_c] = MESH.fetch_global(ub)[slots]
+                gn_o[pos_c] = MESH.fetch_global(gn)[slots]
         return new_global, db_o, ub_o, gn_o
 
     def step_ragged_deferred(self, global_f, store, parts: np.ndarray,
@@ -726,42 +737,46 @@ class RoundExecutor:
         wm = (np.ones(n, np.float32) if wmask is None
               else np.asarray(wmask, np.float32))
         slots32, _ = self._resolve_slots(store, parts, t)
-        g_cdf, g_max = self._hist(global_f)
-        buf, ef = store.pool, store.ef_pool
-        chunks = []
-        call_i = 0
-        for tg in tiers:
-            key = (int(tg.b), int(tg.tau))
-            self.tier_occupancy[key] = (self.tier_occupancy.get(key, 0)
-                                        + len(tg.pos))
-            for pos_c, slots, a in self._tier_chunks(
-                    tg, slots32, theta_d, theta_u,
-                    pad_idx=store.capacity,
-                    cap_per_shard=store.cap_per_shard):
-                c = len(a["parts"])
-                self.work_ragged += c * tg.tau * tg.b
-                self._shapes_seen.add((c, int(tg.tau), int(tg.b)))
-                wm_c = np.zeros(c, np.float32)
-                wm_c[slots] = wm[pos_c]
-                buf, ef, ups, db, ub, gn = self._tier_chunk_defer(
-                    buf, ef, global_f, g_cdf, g_max,
-                    jnp.asarray(a["parts"]), jnp.asarray(wm_c),
-                    jnp.asarray(a["xs"]), jnp.asarray(a["ys"]),
-                    jnp.asarray(a["ws"]), jnp.asarray(a["ims"]), lr,
-                    jnp.asarray(a["td"]), jnp.asarray(a["tu"]),
-                    jnp.uint32(self._round_seed(t, call_i)))
-                call_i += 1
-                chunks.append((pos_c, slots, c, ups, db, ub, gn))
-        store.adopt(buf, ef)
-        self.work_cap += n * self.tau_cap * self.b_cap
+        with span("exec.dispatch", round=t) as sp:
+            g_cdf, g_max = self._hist(global_f)
+            buf, ef = store.pool, store.ef_pool
+            chunks = []
+            call_i = rows = 0
+            for tg in tiers:
+                key = (int(tg.b), int(tg.tau))
+                self.tier_occupancy[key] = (self.tier_occupancy.get(key, 0)
+                                            + len(tg.pos))
+                for pos_c, slots, a in self._tier_chunks(
+                        tg, slots32, theta_d, theta_u,
+                        pad_idx=store.capacity,
+                        cap_per_shard=store.cap_per_shard):
+                    c = len(a["parts"])
+                    rows += c
+                    self.work_ragged += c * tg.tau * tg.b
+                    self._shapes_seen.add((c, int(tg.tau), int(tg.b)))
+                    wm_c = np.zeros(c, np.float32)
+                    wm_c[slots] = wm[pos_c]
+                    buf, ef, ups, db, ub, gn = self._tier_chunk_defer(
+                        buf, ef, global_f, g_cdf, g_max,
+                        jnp.asarray(a["parts"]), jnp.asarray(wm_c),
+                        jnp.asarray(a["xs"]), jnp.asarray(a["ys"]),
+                        jnp.asarray(a["ws"]), jnp.asarray(a["ims"]), lr,
+                        jnp.asarray(a["td"]), jnp.asarray(a["tu"]),
+                        jnp.uint32(self._round_seed(t, call_i)))
+                    call_i += 1
+                    chunks.append((pos_c, slots, c, ups, db, ub, gn))
+            store.adopt(buf, ef)
+            self.work_cap += n * self.tau_cap * self.b_cap
+            sp.set_metadata(rows=rows, valid=n)
         db_o = np.empty(n, np.float32)
         ub_o = np.empty(n, np.float32)
         gn_o = np.empty(n, np.float32)
         # end-of-round readback: every chunk step has been submitted, so
         # these syncs drain the device queue, not stall mid-round
-        for pos_c, slots, _c, _ups, db, ub, gn in chunks:
-            db_o[pos_c] = np.asarray(db)[slots]  # repro: noqa=REP006
-            ub_o[pos_c] = np.asarray(ub)[slots]  # repro: noqa=REP006
-            gn_o[pos_c] = np.asarray(gn)[slots]  # repro: noqa=REP006
+        with span("exec.readback", round=t):
+            for pos_c, slots, _c, _ups, db, ub, gn in chunks:
+                db_o[pos_c] = np.asarray(db)[slots]  # repro: noqa=REP006
+                ub_o[pos_c] = np.asarray(ub)[slots]  # repro: noqa=REP006
+                gn_o[pos_c] = np.asarray(gn)[slots]  # repro: noqa=REP006
         return ([(p, s, c, u) for p, s, c, u, *_ in chunks],
                 db_o, ub_o, gn_o)

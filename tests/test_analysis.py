@@ -84,6 +84,31 @@ def test_shipped_tree_lints_clean():
     assert diags == [], "\n".join(str(d) for d in diags)
 
 
+_SPAN_LOOP = """
+from repro.fl.spans import span
+
+
+class Runner:
+    def run(self, rounds, global_f, parts):
+        for t in range(rounds):
+            global_f, bits = self.step(t, global_f, parts)
+            with span("round.account", round=t, {arg}) as sp:
+                sp.set_metadata(rows={rows})
+"""
+
+
+@pytest.mark.parametrize("arg,rows,flagged", [
+    ("bits=bits", "len(parts)", True),
+    ("parts=len(parts)", "bits.sum()", True),
+    ("parts=len(parts)", "len(parts)", False)])
+def test_rep006_span_arguments_are_sinks(arg, rows, flagged):
+    """A span argument derived from a device value in the round loop
+    would sync the traced round only; a host count is clean."""
+    src = SourceFile("x.py", _SPAN_LOOP.format(arg=arg, rows=rows))
+    diags, _ = lint_source(src, ALL_RULES)
+    assert any(d.rule == "REP006" for d in diags) == flagged, diags
+
+
 # --- the fixed streams actually decorrelated --------------------------------
 
 def test_rng_kinds_decorrelate_streams():

@@ -117,22 +117,14 @@ class TestSimulator:
 
     def test_waiting_history_is_round_aligned_running_mean(self):
         """History.waiting is an eval-aligned RUNNING MEAN over every
-        simulated round (not a 1-in-eval_every subsample); History.wall is
-        the WARM running mean (round 1 carries the one-time jit compile —
-        excluded and reported as compile_s); the raw per-round samples live
-        in waiting_per_round/wall_per_round."""
+        simulated round (not a 1-in-eval_every subsample); the raw
+        per-round samples live in waiting_per_round/wall_per_round."""
         h = Simulator(_cfg(rounds=8, eval_every=4)).run()
-        assert len(h.waiting) == len(h.rounds) == len(h.wall) == 2
+        assert len(h.waiting) == len(h.rounds) == 2
         assert len(h.waiting_per_round) == len(h.wall_per_round) == 8
         for i, t in enumerate(h.rounds):
             np.testing.assert_allclose(
                 h.waiting[i], np.mean(h.waiting_per_round[:t]), rtol=1e-9)
-            np.testing.assert_allclose(
-                h.wall[i], np.mean(h.wall_per_round[1:t]), rtol=1e-9)
-        assert h.compile_s == h.wall_per_round[0]
-        # the compile round is typically an order of magnitude above the
-        # warm mean — it must not be folded into the reported wall
-        assert h.wall[-1] <= np.mean(h.wall_per_round)
 
     def test_eq7_time_model_consistent_with_planner(self):
         """Accounting regression: measured round time / barrier waiting use
