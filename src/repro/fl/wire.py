@@ -21,6 +21,10 @@ Layout (little-endian)::
     ...     ...   values, k × (4 B f32 | 2 B bf16)
     end-4   4     CRC-32 (zlib) over everything before it
 
+Eight indices fill exactly ``idx_bits`` bytes, so the codec packs and
+unpacks them a group of eight at a time in 64-bit words
+(``_pack_indices``); the last group's missing indices are the pad bits.
+
 The CRC is the *only* integrity check — a flipped bit anywhere in header
 or body surfaces as ``WireCRCError`` at decode, which the server answers
 with a single retry request (see the fault engine's retry-once policy).
@@ -92,17 +96,55 @@ class WireUpload:
 
 
 def _pack_indices(indices: np.ndarray, width: int) -> bytes:
-    idx = np.asarray(indices, np.uint64)
-    shifts = np.arange(width - 1, -1, -1, dtype=np.uint64)
-    bits = ((idx[:, None] >> shifts) & np.uint64(1)).astype(np.uint8)
-    return np.packbits(bits.ravel()).tobytes()
+    """The low ``width`` bits of each index, MSB-first, zero-padded to a
+    byte. Eight indices fill exactly ``width`` bytes, so each group of
+    eight is assembled as ceil(width / 8) big-endian 64-bit words (an
+    index straddles at most two) of which the first ``width`` bytes are
+    kept; a short last group is padded with zero indices, the pad bits."""
+    k = len(indices)
+    idx = np.zeros(-(-k // 8) * 8, np.uint64)
+    idx[:k] = indices
+    idx &= np.uint64((1 << width) - 1)
+    grp = idx.reshape(-1, 8).T.copy()        # grp[j]: index j of each group
+    words = np.empty((grp.shape[1], -(-width // 8)), ">u8")
+    for q in range(words.shape[1]):
+        word = np.zeros(grp.shape[1], np.uint64)
+        for j in range(8):
+            # index j holds group bits [j*width, (j+1)*width), word q bits
+            # [64q, 64q + 64), both counted from the MSB: a left shift by
+            # s lines them up (right by -s); s outside (-width, 64) means
+            # they do not overlap
+            s = 64 * q + 64 - (j + 1) * width
+            if 0 <= s < 64:
+                word |= grp[j] << np.uint64(s)
+            elif -width < s < 0:
+                word |= grp[j] >> np.uint64(-s)
+        words[:, q] = word
+    # each group's first ``width`` bytes, back to back
+    rows = np.ndarray(len(words), f"V{width}", words, 0, (words.strides[0],))
+    return rows.tobytes()[:(k * width + 7) // 8]
 
 
-def _unpack_indices(buf: bytes, k: int, width: int) -> np.ndarray:
-    bits = np.unpackbits(np.frombuffer(buf, np.uint8), count=k * width)
-    bits = bits.reshape(k, width).astype(np.uint64)
-    shifts = np.arange(width - 1, -1, -1, dtype=np.uint64)
-    return (bits << shifts).sum(axis=1).astype(np.int32)
+def _unpack_indices(buf, k: int, width: int) -> np.ndarray:
+    """Inverse of ``_pack_indices``: index j of each group of eight starts
+    at bit ``j * width`` of its ``width``-byte group, so one strided
+    64-bit window per j, read big-endian, holds it whole (offset <= 7
+    bits plus width <= 32 bits)."""
+    groups = -(-k // 8)
+    # zeros past the payload: the last windows read up to 8 bytes beyond
+    # their group, and numpy checks every window's offset, k = 0 included
+    padded = np.zeros((groups + 1) * width + 8, np.uint8)
+    padded[:len(buf)] = np.frombuffer(buf, np.uint8)
+    mask = np.uint64((1 << width) - 1)
+    out = np.empty((groups, 8), np.uint32)
+    for j in range(8):
+        bit = j * width
+        # a little-endian read then a byte swap: numpy's strided
+        # big-endian read is several times slower
+        window = np.ndarray(groups, "<u8", padded, bit >> 3,
+                            (width,)).byteswap()
+        out[:, j] = (window >> np.uint64(64 - (bit & 7) - width)) & mask
+    return out.ravel()[:k].astype(np.int32)
 
 
 def f32_to_bf16_bytes(values: np.ndarray) -> bytes:
@@ -148,6 +190,7 @@ def decode_upload(buf: bytes) -> WireUpload:
     if len(buf) < HEADER_BYTES + CRC_BYTES:
         raise WireFormatError(f"payload truncated at {len(buf)} B")
     (crc,) = struct.unpack_from("<I", buf, len(buf) - CRC_BYTES)
+    buf = memoryview(buf)    # slices below share the payload's bytes
     if zlib.crc32(buf[:-CRC_BYTES]) != crc:
         raise WireCRCError("CRC-32 mismatch")
     magic, version, dflag, client, round_, n_params, k = \

@@ -5,6 +5,8 @@ retries, so the codec's contract is load-bearing:
 
 * round-trip exactness — indices and values come back bit-identical,
   for f32 and bf16 value payloads;
+* packing — the index bitpacking gives, byte for byte, what the original
+  per-bit packer gave (kept below as the oracle) at every width 1-32;
 * payload size — ``payload_nbytes`` is EXACT (header + ceil(log2 n)-bit
   packed indices + values + CRC-32), since modeled traffic accounting
   and the measured wire bytes must agree;
@@ -31,6 +33,56 @@ def _upload(n_params=1000, k=37, seed=3, dtype="float32"):
     payload = W.encode_upload(idx, vals, client=7, round_=5,
                               n_params=n_params, value_dtype=dtype)
     return idx, vals, payload
+
+
+def _pack_oracle(indices, width):
+    """The original packer: a [k, width] matrix of single bits, packed."""
+    idx = np.asarray(indices, np.uint64)
+    shifts = np.arange(width - 1, -1, -1, dtype=np.uint64)
+    bits = ((idx[:, None] >> shifts) & np.uint64(1)).astype(np.uint8)
+    return np.packbits(bits.ravel()).tobytes()
+
+
+def _unpack_oracle(buf, k, width):
+    bits = np.unpackbits(np.frombuffer(buf, np.uint8), count=k * width)
+    bits = bits.reshape(k, width).astype(np.uint64)
+    shifts = np.arange(width - 1, -1, -1, dtype=np.uint64)
+    return (bits << shifts).sum(axis=1).astype(np.int32)
+
+
+class TestIndexPacking:
+    @pytest.mark.parametrize("k", [0, 1, 7, 8, 9, 1000])
+    @pytest.mark.parametrize("width", range(1, 33))
+    def test_matches_the_per_bit_oracle(self, width, k):
+        top = (1 << width) - 1
+        rng = RNG.stream(width, RNG.KIND_FAULTS, 98, k)
+        idx = rng.integers(0, top, size=k, endpoint=True, dtype=np.int64)
+        idx[:2] = [0, top][:k]
+        packed = W._pack_indices(idx, width)
+        assert packed == _pack_oracle(idx, width)
+        assert len(packed) == (k * width + 7) // 8
+        got = W._unpack_indices(packed, k, width)
+        assert got.dtype == np.int32
+        np.testing.assert_array_equal(got, _unpack_oracle(packed, k, width))
+        # encode keeps the low ``width`` bits of an index past the width
+        high = idx + (rng.integers(1, 8, size=k) << width)
+        assert W._pack_indices(high, width) == _pack_oracle(high, width)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("k", [16_413, 98_480])
+    def test_har_sized_round_trip(self, k, dtype, monkeypatch):
+        # the HAR CNN's 164,134 parameters (18-bit indices) at theta_u
+        # 0.1 and 0.6
+        n_params = 164_134
+        idx, vals, payload = _upload(n_params=n_params, k=k, dtype=dtype)
+        assert len(payload) == W.payload_nbytes(n_params, k, dtype)
+        u = W.decode_upload(payload)
+        np.testing.assert_array_equal(u.indices, idx)
+        expect = (vals if dtype == "float32" else
+                  W.bf16_bytes_to_f32(W.f32_to_bf16_bytes(vals)))
+        np.testing.assert_array_equal(u.values, expect)
+        monkeypatch.setattr(W, "_pack_indices", _pack_oracle)
+        assert payload == _upload(n_params=n_params, k=k, dtype=dtype)[2]
 
 
 class TestCodec:
